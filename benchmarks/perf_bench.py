@@ -10,7 +10,10 @@ Measures, per cell (N × {dense, sparse, sparse+eval cadence}):
 plus a **sharded-sweep throughput cell** (``benchmarks/shard_bench.py``, run
 as a subprocess so its forced 8-device host platform cannot skew the
 single-device cells): the same seeds-grid swept with ``run_sweep(devices=1)``
-vs ``devices=8``, recording the scale-out speedup of the cells mesh.
+vs ``devices=8``, recording the scale-out speedup of the cells mesh. That
+child, the popscale child and the lint child run with ``JAX_PLATFORMS=cpu``
+(they measure forced host devices, and the parent holds any accelerator);
+their cells are labelled ``"device": "cpu"``.
 
 Writes ``benchmarks/results/BENCH_perf.json`` — the artifact CI uploads per
 commit, with the headline ``speedup_n100`` = hot path (sparse gather +
@@ -21,6 +24,7 @@ eval_every cadence) over the dense path at the paper's N=100, K=10.
 from __future__ import annotations
 
 import json
+import os
 import platform
 import subprocess
 import sys
@@ -37,9 +41,11 @@ from repro.core.sweep import sweep_point_from_config
 from repro.data.synthetic import make_fmnist_like
 from repro.federated.partition import sorted_label_shards
 from repro.models.logreg import logistic_regression
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.tree import tree_size
 
-RESULTS = Path(__file__).resolve().parent / "results"
+REPO = Path(__file__).resolve().parent.parent
+RESULTS = REPO / "benchmarks" / "results"
 
 DIM = 784  # the paper's FMNIST logreg: M = 7850
 
@@ -99,6 +105,18 @@ def bench_cell(model, fl, data, dense: bool):
     }
 
 
+def _host_child(*args):
+    """Run ``python -m <args>`` on forced CPU host devices.
+
+    The parent already holds the process's accelerator (a chip belongs to one
+    process), and these cells measure forced host devices by design, so the
+    child is pinned to the CPU and its result is labelled ``cpu``.
+    """
+    return subprocess.run(
+        [sys.executable, "-m", *args], capture_output=True, text=True,
+        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu"})
+
+
 def _write(payload):
     RESULTS.mkdir(parents=True, exist_ok=True)
     with open(RESULTS / "BENCH_perf.json", "w") as f:
@@ -106,14 +124,17 @@ def _write(payload):
 
 
 def main():
+    enable_compile_cache()
     model = logistic_regression(DIM, 10)
+    dev = jax.devices()[0]
     payload = {
         "bench": "perf_bench",
         "model": f"logreg dim={DIM} (M={DIM * 10 + 10})",
         "clients_per_round": K,
         "jax_version": jax.__version__,
         "platform": platform.platform(),
-        "device": jax.devices()[0].platform,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": jax.device_count()},
         "cells": {},
     }
     for n, rounds in GRIDS:
@@ -170,11 +191,9 @@ def main():
     # ---- sharded-sweep scale-out cell (subprocess: needs its own 8-device
     # host platform, which must not leak into the cells above) -------------
     try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.shard_bench"],
-            capture_output=True, text=True, check=True,
-            cwd=Path(__file__).resolve().parent.parent)
-        shard = json.loads(proc.stdout)
+        proc = _host_child("benchmarks.shard_bench")
+        proc.check_returncode()
+        shard = {**json.loads(proc.stdout), "device": "cpu"}
         payload["cells"]["sharded_sweep"] = shard
         print(f"[perf_bench] sharded sweep: devices=8 "
               f"{shard['speedup_devices8']:.2f}x devices=1 "
@@ -192,11 +211,9 @@ def main():
     # clients; popscale_bench itself enforces the O(N/D) per-device-memory
     # ceiling and fails the job on a replication regression ----------------
     try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.popscale_bench"],
-            capture_output=True, text=True, check=True,
-            cwd=Path(__file__).resolve().parent.parent)
-        pop = json.loads(proc.stdout)
+        proc = _host_child("benchmarks.popscale_bench")
+        proc.check_returncode()
+        pop = {**json.loads(proc.stdout), "device": "cpu"}
         payload["cells"]["popscale"] = pop
         big = max(pop["cells"].values(), key=lambda c: c["n_clients"])
         print(f"[perf_bench] popscale: N={big['n_clients']:,} at "
@@ -220,14 +237,11 @@ def main():
     report = RESULTS / "lint-report.json"
     RESULTS.mkdir(parents=True, exist_ok=True)
     t0 = time.perf_counter()
-    proc = subprocess.run(
-        [sys.executable, "-m", "repro.lint", "--jaxpr", "--json",
-         str(report)],
-        capture_output=True, text=True,
-        cwd=Path(__file__).resolve().parent.parent)
+    proc = _host_child("repro.lint", "--jaxpr", "--json", str(report))
     lint_wall = time.perf_counter() - t0
     lint_report = json.loads(report.read_text())
     payload["cells"]["lint"] = {
+        "device": "cpu",
         "wall_seconds": lint_wall,
         "ast_seconds": lint_report["ast"]["seconds"],
         "jaxpr_seconds": lint_report["jaxpr"]["seconds"],

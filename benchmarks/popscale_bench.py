@@ -124,7 +124,6 @@ def bench_projection():
     device count (and therefore N) grows: O(N/D + iters) means the per-call
     wall time must stay flat — the gather+sort it replaced grew O(N log N)
     on every device."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     rows = []
@@ -132,10 +131,10 @@ def bench_projection():
         n = PROJ_LOCAL * d
         mesh = sharding.client_mesh(d)
         ax = mesh.axis_names[0]
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda v, ax=ax: sharding.project_simplex_sharded(
                 v, axis_name=ax),
-            mesh=mesh, in_specs=P(ax), out_specs=P(ax), check_rep=False))
+            mesh=mesh, in_specs=P(ax), out_specs=P(ax), check_vma=False))
         v = jax.device_put(
             jax.random.normal(jax.random.PRNGKey(0), (n,), jnp.float32),
             NamedSharding(mesh, P(ax)))

@@ -10,6 +10,9 @@ import sys
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     full = "--full" in sys.argv
     from benchmarks import micro, paper_figs, roofline_table
 

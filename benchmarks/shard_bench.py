@@ -57,14 +57,15 @@ def _time_run(model, data, fl, devices):
     seeds_arr = jnp.asarray(SEEDS, jnp.int32)
     model_size = tree_size(model.init(jax.random.PRNGKey(0)))
     init_fn, runner = sweep._build_runner(
-        model, fl, data, fl.method, noise_free=fl.noise_std == 0,
+        model, fl, fl.method, noise_free=fl.noise_std == 0,
         model_size=model_size, mesh=mesh)
+    data = tuple(jnp.asarray(d) for d in data)
     states = init_fn(point, seeds_arr)
-    states, hist = runner(point, states)  # warm-up: compile + execute
+    states, hist = runner(point, states, data)  # warm-up: compile + execute
     jax.block_until_ready(hist)
     t0 = time.perf_counter()
     for _ in range(REPS):
-        states, hist = runner(point, states)
+        states, hist = runner(point, states, data)
     jax.block_until_ready((states, hist))
     return (time.perf_counter() - t0) / REPS
 

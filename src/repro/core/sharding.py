@@ -65,7 +65,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -529,10 +528,10 @@ def run_simulation_sharded(model, fl, data, mesh: Mesh, seed=None,
             hist = hist._replace(lam=final.lam_snaps)
         return hist
 
-    shard_mapped = shard_map(
+    shard_mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
     sharded_data = tuple(shard_leading(jnp.asarray(d), mesh, axis)
                          for d in data)
     return jax.jit(shard_mapped)(point, state, *sharded_data)
@@ -603,10 +602,10 @@ def build_control_sharded_runner(model, fl, data, mesh: Mesh,
 
     run = control_sharded_cell_run(model, fl, fl.method, axis, n_local,
                                    model_size, group_size=group_size)
-    shard_mapped = shard_map(
+    shard_mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=control_sharded_history_specs(fl, axis), check_rep=False)
+        out_specs=control_sharded_history_specs(fl, axis), check_vma=False)
     sharded_data = tuple(shard_leading(jnp.asarray(d), mesh, axis)
                          for d in data)
     return jax.jit(shard_mapped), point, sharded_data

@@ -1109,11 +1109,13 @@ def run_simulation(
     state = init_sim_state(model, fl, jax.random.PRNGKey(seed),
                            process=point.process)
     model_size = tree_size(state.w)
-    round_fn = make_param_round_fn(model, fl, data, model_size, fl.method,
-                                   dense=dense)
 
     @jax.jit
-    def run(point, state):
+    def run(point, state, data):
+        # data is an argument, not a closed-over constant (see
+        # sweep._build_runner)
+        round_fn = make_param_round_fn(model, fl, data, model_size, fl.method,
+                                       dense=dense)
         final, hist = jax.lax.scan(
             lambda s, t: round_fn(point, s, t), state, jnp.arange(fl.rounds))
         if fl.record_lambda_every > 1:
@@ -1122,7 +1124,7 @@ def run_simulation(
             hist = hist._replace(lam=final.lam_snaps)
         return hist
 
-    return run(point, state)
+    return run(point, state, tuple(jnp.asarray(d) for d in data))
 
 
 def run_multi_seed(model: SimModel, fl: FLConfig, data, seeds) -> SimHistory:

@@ -4,7 +4,10 @@ Fuses the per-client gain/mask scale, the superposition sum over the client
 axis, the AWGN injection and the 1/K normalization into one pass over the
 model dimension — one HBM read of the [N, M] stacked updates, one HBM write
 of the [M] aggregate. Blocked over M with VMEM tiles of [N, TILE_M]; the
-weighted reduction over N runs on the VPU as an fp32 accumulation.
+weighted reduction over N runs on the VPU as an fp32 accumulation. The noise
+``z`` and the aggregate ride as [1, M] rows with (1, TILE_M) blocks: under
+``vmap`` (the sweep's seed and point axes) a 1-D (TILE_M,) block would turn
+into a (1, TILE_M) slice of an [R, M] array, which the TPU lowering refuses.
 
 ``noise_std`` and ``k`` ride in as (1, 1) SMEM scalars, NOT static compile
 args: the simulator traces both (the receiver noise is a sweepable scenario
@@ -27,12 +30,26 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 TILE_M = 1024  # lane-dim tile; multiple of 128
+LANE = 128
+
+
+def _blocking(m: int) -> tuple[int, int]:
+    """(tile, pad) for a model dimension of ``m`` columns.
+
+    M is zero-padded up to a multiple of the tile, and the tile is at most
+    TILE_M lanes and always a multiple of 128: a whole unaligned [C, M] row
+    block never lands in VMEM, whatever M is. Each output column is a sum
+    over clients only, so the padded columns are sliced off without
+    touching the real ones.
+    """
+    tile = min(TILE_M, -(-m // LANE) * LANE)
+    return tile, (-m) % tile
 
 
 def _aircomp_kernel(ns_ref, ik_ref, x_ref, w_ref, z_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)          # [N, TM]
     w = w_ref[...].astype(jnp.float32)          # [N, 1]
-    acc = jnp.sum(x * w, axis=0)                # [TM]
+    acc = jnp.sum(x * w, axis=0, keepdims=True)  # [1, TM]
     acc = acc + ns_ref[0, 0] * z_ref[...].astype(jnp.float32)
     o_ref[...] = acc * ik_ref[0, 0]
 
@@ -60,7 +77,7 @@ def _quant_aircomp_kernel(ns_ref, ik_ref, x_ref, w_ref, d_ref, u_ref, z_ref,
     d = d_ref[...].astype(jnp.float32)          # [C, 1]
     safe = jnp.where(d > 0, d, 1.0)
     q = jnp.where(d > 0, jnp.floor(x / safe + u) * d, x)
-    acc = jnp.sum(q * w, axis=0)                # [TM]
+    acc = jnp.sum(q * w, axis=0, keepdims=True)  # [1, TM]
     acc = acc + ns_ref[0, 0] * z_ref[...].astype(jnp.float32)
     o_ref[...] = acc * ik_ref[0, 0]
 
@@ -81,7 +98,7 @@ def _sparse_aircomp_kernel(ns_ref, ik_ref, x_ref, w_ref, t_ref, z_ref,
     w = w_ref[...].astype(jnp.float32)          # [C, 1]
     t = t_ref[...].astype(jnp.float32)          # [C, 1]
     c = jnp.where(jnp.abs(x) >= t, x, 0.0)
-    acc = jnp.sum(c * w, axis=0)                # [TM]
+    acc = jnp.sum(c * w, axis=0, keepdims=True)  # [1, TM]
     acc = acc + ns_ref[0, 0] * z_ref[...].astype(jnp.float32)
     o_ref[...] = acc * ik_ref[0, 0]
 
@@ -92,14 +109,13 @@ def sparse_aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, thr: jnp.ndarray,
                           interpret: bool = False) -> jnp.ndarray:
     """x [C, M]; w/thr [C]; z [M] -> sparse-compressed aggregate [M] fp32.
 
-    Same blocking as :func:`quant_aircomp_pallas` (M padded to TILE_M, C
-    whole in VMEM); ``noise_std``/``k`` ride as (1, 1) SMEM scalars. A
+    Same blocking as :func:`quant_aircomp_pallas` (M padded to whole tiles,
+    C whole in VMEM); ``noise_std``/``k`` ride as (1, 1) SMEM scalars. A
     zero-padded column passes the mask only when thr_c = 0 (an all-zero
     payload row) and then contributes w·0 = 0, so padding never leaks.
     """
     c, m = x.shape
-    tile = min(TILE_M, m) if m % 128 == 0 else m
-    pad = (-m) % tile
+    tile, pad = _blocking(m)
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
         z = jnp.pad(z, (0, pad))
@@ -118,13 +134,13 @@ def sparse_aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, thr: jnp.ndarray,
             pl.BlockSpec((c, tile), lambda i: (0, i)),
             pl.BlockSpec((c, 1), lambda i: (0, 0)),
             pl.BlockSpec((c, 1), lambda i: (0, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.float32),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, mp), jnp.float32),
         interpret=interpret,
-    )(ns, inv_k, x, w[:, None], thr[:, None], z)
-    return out[:m]
+    )(ns, inv_k, x, w[:, None], thr[:, None], z[None])
+    return out[0, :m]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -134,14 +150,13 @@ def quant_aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, d: jnp.ndarray,
                          ) -> jnp.ndarray:
     """x/u [C, M]; w/d [C]; z [M] -> quantized aggregate [M] fp32.
 
-    Same blocking as :func:`aircomp_pallas` (M padded to TILE_M, C whole in
-    VMEM); ``noise_std``/``k`` ride as (1, 1) SMEM scalars per the kernel
-    docstring. The zero-padded columns quantize to exact zeros (⌊0 + u⌋ = 0
+    Same blocking as :func:`aircomp_pallas` (M padded to whole tiles, C
+    whole in VMEM); ``noise_std``/``k`` ride as (1, 1) SMEM scalars per the
+    kernel docstring. The zero-padded columns quantize to exact zeros (⌊0 + u⌋ = 0
     for u < 1), so padding never leaks into the output.
     """
     c, m = x.shape
-    tile = min(TILE_M, m) if m % 128 == 0 else m
-    pad = (-m) % tile
+    tile, pad = _blocking(m)
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
         u = jnp.pad(u, ((0, 0), (0, pad)))
@@ -162,13 +177,13 @@ def quant_aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, d: jnp.ndarray,
             pl.BlockSpec((c, 1), lambda i: (0, 0)),
             pl.BlockSpec((c, 1), lambda i: (0, 0)),
             pl.BlockSpec((c, tile), lambda i: (0, i)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.float32),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, mp), jnp.float32),
         interpret=interpret,
-    )(ns, inv_k, x, w[:, None], d[:, None], u, z)
-    return out[:m]
+    )(ns, inv_k, x, w[:, None], d[:, None], u, z[None])
+    return out[0, :m]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -177,12 +192,12 @@ def aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, z: jnp.ndarray,
     """x [N, M]; w [N]; z [M] -> aggregated [M] fp32.
 
     ``noise_std`` and ``k`` may be Python floats or traced jnp scalars. M is
-    padded to TILE_M internally; N rides whole in VMEM (N=100 clients x
-    1024 lanes x 4B = 400 KiB << 16 MiB VMEM).
+    zero-padded to a whole number of tiles (:func:`_blocking`) and the pad
+    columns are sliced off; N rides whole in VMEM (N=100 clients x 1024
+    lanes x 4B = 400 KiB << 16 MiB VMEM).
     """
     n, m = x.shape
-    tile = min(TILE_M, m) if m % 128 == 0 else m
-    pad = (-m) % tile
+    tile, pad = _blocking(m)
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad)))
         z = jnp.pad(z, (0, pad))
@@ -200,10 +215,10 @@ def aircomp_pallas(x: jnp.ndarray, w: jnp.ndarray, z: jnp.ndarray,
             scalar_spec,
             pl.BlockSpec((n, tile), lambda i: (0, i)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
+            pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((mp,), jnp.float32),
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, mp), jnp.float32),
         interpret=interpret,
-    )(ns, inv_k, x, w[:, None], z)
-    return out[:m]
+    )(ns, inv_k, x, w[:, None], z[None])
+    return out[0, :m]

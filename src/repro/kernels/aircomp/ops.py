@@ -1,8 +1,14 @@
 """Dispatching wrapper for the AirComp aggregation kernel.
 
-On TPU the Pallas kernel runs compiled; everywhere else (this CPU container)
-it runs in interpret mode for correctness work, falling back to the jnp
-oracle for speed when ``interpret=False`` is requested off-TPU. The Pallas
+The choice between the Pallas kernel and the jnp oracle is made for the
+platform the program is LOWERED for (``lax.platform_dependent``), not for the
+process's default backend: the same traced round runs the compiled kernel
+when it is placed on a TPU and the oracle when it is placed on a CPU device,
+and a compile for a described TPU that is not attached picks the kernel too.
+
+``use_pallas``: ``None`` (default) = compiled Pallas on TPU, the jnp oracle
+elsewhere; ``True`` = Pallas everywhere (compiled on TPU, interpret mode
+elsewhere, for correctness work); ``False`` = the jnp oracle. The Pallas
 kernel accumulates in f32 only: buffers wider than f32 (float64 models) are
 routed to the dtype-preserving jnp oracle regardless of backend, so enabling
 x64 never silently truncates through the kernel.
@@ -19,8 +25,22 @@ from repro.kernels.aircomp.ref import (aircomp_ref, quant_aircomp_ref,
                                        sparse_aircomp_ref)
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _dispatch(pallas_fn, ref_fn, use_pallas, *arrays, noise_std, k):
+    """Run ``pallas_fn`` or ``ref_fn`` on ``(*arrays, noise_std, k)`` per the
+    module docstring's ``use_pallas`` rule."""
+    if use_pallas is False or jnp.dtype(arrays[0].dtype).itemsize > 4:
+        # f64 accumulation: the Pallas kernel is f32-only — keep precision
+        return ref_fn(*arrays, noise_std, k)
+
+    def kernel(interpret):
+        return lambda *a: pallas_fn(*a[:-2], noise_std=a[-2], k=a[-1],
+                                    interpret=interpret)
+
+    other = kernel(True) if use_pallas else (lambda *a: ref_fn(*a))
+    # noise_std/k may be traced: they ride as operands, never as closures
+    return jax.lax.platform_dependent(
+        *arrays, jnp.asarray(noise_std, jnp.float32),
+        jnp.asarray(k, jnp.float32), tpu=kernel(False), default=other)
 
 
 def aircomp_aggregate_flat(x: jnp.ndarray, w: jnp.ndarray, z: jnp.ndarray,
@@ -32,15 +52,8 @@ def aircomp_aggregate_flat(x: jnp.ndarray, w: jnp.ndarray, z: jnp.ndarray,
     former and computes the latter from the round's actual scheduled count);
     both paths accept them without recompiling per value.
     """
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if jnp.dtype(x.dtype).itemsize > 4:
-        # f64 accumulation: the Pallas kernel is f32-only — keep precision
-        use_pallas = False
-    if use_pallas:
-        return aircomp_pallas(x, w, z, noise_std=noise_std, k=k,
-                              interpret=not on_tpu())
-    return aircomp_ref(x, w, z, noise_std, k)
+    return _dispatch(aircomp_pallas, aircomp_ref, use_pallas, x, w, z,
+                     noise_std=noise_std, k=k)
 
 
 def quant_aircomp_flat(x: jnp.ndarray, w: jnp.ndarray, d: jnp.ndarray,
@@ -51,18 +64,10 @@ def quant_aircomp_flat(x: jnp.ndarray, w: jnp.ndarray, d: jnp.ndarray,
 
     ``d`` [C] per-client stochastic-rounding steps, ``u`` [C, M] pre-drawn
     rounding uniforms (see ``core/transport.quantize_rows`` for the key
-    discipline). Dispatch mirrors :func:`aircomp_aggregate_flat`: Pallas on
-    TPU / interpret off-TPU when forced, the jnp oracle otherwise, and
-    always the dtype-preserving oracle for wider-than-f32 buffers.
+    discipline). Dispatch as in the module docstring.
     """
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if jnp.dtype(x.dtype).itemsize > 4:
-        use_pallas = False
-    if use_pallas:
-        return quant_aircomp_pallas(x, w, d, u, z, noise_std=noise_std, k=k,
-                                    interpret=not on_tpu())
-    return quant_aircomp_ref(x, w, d, u, z, noise_std, k)
+    return _dispatch(quant_aircomp_pallas, quant_aircomp_ref, use_pallas,
+                     x, w, d, u, z, noise_std=noise_std, k=k)
 
 
 def sparse_aircomp_flat(x: jnp.ndarray, w: jnp.ndarray, thr: jnp.ndarray,
@@ -73,16 +78,8 @@ def sparse_aircomp_flat(x: jnp.ndarray, w: jnp.ndarray, thr: jnp.ndarray,
 
     ``thr`` [C] per-client magnitude thresholds (see
     ``core/transport.sparse_thresholds`` — the top-k runs outside the
-    kernel, compression inside is one compare-and-mask). Dispatch mirrors
-    :func:`quant_aircomp_flat`: Pallas on TPU / interpret off-TPU when
-    forced, the jnp oracle otherwise, and always the dtype-preserving
-    oracle for wider-than-f32 buffers.
+    kernel, compression inside is one compare-and-mask). Dispatch as in the
+    module docstring.
     """
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    if jnp.dtype(x.dtype).itemsize > 4:
-        use_pallas = False
-    if use_pallas:
-        return sparse_aircomp_pallas(x, w, thr, z, noise_std=noise_std, k=k,
-                                     interpret=not on_tpu())
-    return sparse_aircomp_ref(x, w, thr, z, noise_std, k)
+    return _dispatch(sparse_aircomp_pallas, sparse_aircomp_ref, use_pallas,
+                     x, w, thr, z, noise_std=noise_std, k=k)

@@ -22,9 +22,10 @@ import numpy as np
 from repro.configs import get_config, get_reduced
 from repro.configs.base import FLConfig
 from repro.data.synthetic import make_lm_tokens
-from repro.federated.server import ParameterServer
+from repro.federated.server import ParameterServer, ServerState
 from repro.models.api import build_model
 from repro.optim import sgd, adamw
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def lm_batches(corpus: np.ndarray, batch_per_client: int, seq: int,
@@ -54,7 +55,10 @@ def lm_batches(corpus: np.ndarray, batch_per_client: int, seq: int,
         yield batch
 
 
-def main():
+def main(argv=None) -> ServerState:
+    """Parse ``argv`` (default: ``sys.argv[1:]``), train, return the final
+    server state (callers in-process read ``state.history``)."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -71,7 +75,7 @@ def main():
     ap.add_argument("--server-opt", default="sgd", choices=["sgd", "adamw"])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     cfg = cfg.with_(dtype="float32", remat=False)
@@ -101,6 +105,7 @@ def main():
     if args.out:
         Path(args.out).write_text(json.dumps(state.history, indent=2))
         print(f"history -> {args.out}")
+    return state
 
 
 if __name__ == "__main__":

@@ -35,7 +35,6 @@ from dataclasses import replace
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import FLConfig
@@ -147,11 +146,11 @@ def trace_sharded_round(method: str, transport: str = "analog",
     point = sweep_point_from_config(fl)
     run = sharding.control_sharded_cell_run(
         model, fl, method, AXIS, N, model_size)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(), P(), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
         out_specs=sharding.control_sharded_history_specs(fl, AXIS),
-        check_rep=False)
+        check_vma=False)
     return jax.make_jaxpr(mapped)(point, jax.random.PRNGKey(0), *data)
 
 
@@ -174,9 +173,9 @@ def trace_replicated_round(method: str = "ca_afl",
 def trace_projection():
     """Jaxpr of ``project_simplex_sharded`` alone on the size-1 mesh."""
     _, _, _, mesh = _setup()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda v: sharding.project_simplex_sharded(v, AXIS), mesh=mesh,
-        in_specs=(P(AXIS),), out_specs=P(AXIS), check_rep=False)
+        in_specs=(P(AXIS),), out_specs=P(AXIS), check_vma=False)
     return jax.make_jaxpr(mapped)(jnp.ones((N,), jnp.float32))
 
 
@@ -255,11 +254,11 @@ def check_sweep_donation():
     model, data, model_size, _ = _setup()
     fl = _fl("fedavg", control_plane="replicated")
     init_fn, runner = sweep_mod._build_runner(
-        model, fl, data, "fedavg", noise_free=True, model_size=model_size)
+        model, fl, "fedavg", noise_free=True, model_size=model_size)
     points = sweep_mod._stack_points([sweep_point_from_config(fl)])
     seeds = jnp.asarray([0], jnp.int32)
     states = init_fn(points, seeds)
-    text = runner.lower(points, states).as_text()
+    text = runner.lower(points, states, data).as_text()
     if "tf.aliasing_output" not in text and "jax.buffer_donor" not in text:
         return False, ("no input->output aliasing marker in the sweep "
                        "runner's StableHLO — donate_argnums lost")

@@ -274,7 +274,6 @@ def test_control_sharded_rejects_replicated_config():
 
 
 def _run_hier_top_k(scores, k, group_size=None):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = sharding.client_mesh(
@@ -286,8 +285,8 @@ def _run_hier_top_k(scores, k, group_size=None):
         return sharding.hierarchical_top_k(s, k, ax, n_shards,
                                            group_size=group_size)
 
-    fn = shard_map(body, mesh=mesh, in_specs=P(ax), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(ax), out_specs=P(),
+                       check_vma=False)
     return np.asarray(jax.jit(fn)(scores))
 
 
@@ -385,14 +384,13 @@ def test_control_sharded_large_population_smoke():
 
 
 def _run_sharded_projection(v, n_dev):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = sharding.client_mesh(n_dev)
     ax = mesh.axis_names[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda s: sharding.project_simplex_sharded(s, axis_name=ax),
-        mesh=mesh, in_specs=P(ax), out_specs=P(ax), check_rep=False)
+        mesh=mesh, in_specs=P(ax), out_specs=P(ax), check_vma=False)
     return np.asarray(jax.jit(fn)(v))
 
 

@@ -1,5 +1,6 @@
 """Distribution machinery: HLO cost analyzer, spec selection, small-mesh
 end-to-end sharded round, and a subprocess dry-run on a tiny forced mesh."""
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -170,12 +171,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_reduced
+from repro.launch.mesh import make_host_mesh
 from repro.models.api import build_model
 from repro.models.specs import ShardingCtx
 from repro.federated.rounds import make_fl_round
 from repro.optim import sgd
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(4, 2)
 cfg = get_reduced("qwen2-0.5b").with_(dtype="float32", remat=False,
                                       d_model=256, num_heads=4, num_kv_heads=2)
 ctx = ShardingCtx(mesh)
@@ -221,7 +223,6 @@ def test_sharded_round_matches_unsharded():
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_SCRIPT],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
-             "HOME": "/tmp"},
+        env={**os.environ, "PYTHONPATH": str(REPO / "src")},
         cwd=str(REPO))
     assert "SHARDED_OK" in res.stdout, res.stderr[-3000:]

@@ -175,16 +175,15 @@ def test_population_device_count_divides():
 
 
 def _run_distributed_top_k(scores, k):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = sharding.client_mesh(
         sharding.population_device_count(scores.shape[0]))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda s: sharding.distributed_top_k(
             s, k, mesh.axis_names[0], n_global=scores.shape[0]),
         mesh=mesh, in_specs=P(mesh.axis_names[0]), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn)(scores)
 
 

@@ -302,6 +302,38 @@ def test_sparse_kernel_matches_reference():
     f(0.1, 3.0)  # same executable, different scalars
 
 
+@pytest.mark.parametrize("kind", ["analog", "quantized", "sparse"])
+def test_kernels_match_reference_at_unaligned_width(kind):
+    """M = 3,001 is no multiple of 128: the kernels zero-pad it to whole
+    TILE_M tiles (three tiles, the last mostly padding) and slice the pad
+    off. Pallas (interpret) must equal the jnp oracle column for column."""
+    from repro.kernels.aircomp.ops import (aircomp_aggregate_flat,
+                                           sparse_aircomp_flat)
+    from repro.core.transport import sparse_thresholds
+
+    key = jax.random.PRNGKey(11)
+    c, m = 6, 3_001
+    x = jax.random.normal(key, (c, m))
+    w = jnp.asarray([1.0, 0.0, 1.0, 1.0, 0.0, 1.0])
+    z = jax.random.normal(jax.random.fold_in(key, 2), (m,))
+    if kind == "analog":
+        agg = lambda p: aircomp_aggregate_flat(x, w, z, noise_std=0.3, k=4.0,
+                                               use_pallas=p)
+    elif kind == "quantized":
+        d = quant_step(x, 6.0)
+        u = jax.random.uniform(jax.random.fold_in(key, 1), (c, m))
+        agg = lambda p: quant_aircomp_flat(x, w, d, u, z, noise_std=0.3,
+                                           k=4.0, use_pallas=p)
+    else:
+        thr = sparse_thresholds(x, 150)
+        agg = lambda p: sparse_aircomp_flat(x, w, thr, z, noise_std=0.3,
+                                            k=4.0, use_pallas=p)
+    ref, pal = agg(False), agg(True)  # True: interpret mode off-TPU
+    assert pal.shape == (m,)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               rtol=1e-6, atol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # Differential pins: analog bit-identity, bits=32 ≈ analog, digital == mean
 # ---------------------------------------------------------------------------
