@@ -22,6 +22,7 @@ Both rounds name their device work with ``jax.named_scope``: ``descent``,
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -220,26 +221,60 @@ def _make_gather_round(model, optimizer, num_clients: int, noise_std, ctx):
     return round_fn
 
 
+# Bound on the elements one step of ``add_awgn``'s loop draws: 16 MiB of f32
+# normals (and as much of threefry bits) at a time.
+NOISE_BLOCK_ELEMS = 1 << 22
+
+
 def add_awgn(grads, key, std: float):
     """z ~ N(0, std²) elementwise on every leaf (eq. 10's receiver noise).
 
-    Leaves with a stacked leading (layer) axis generate noise one slice at a
-    time via lax.scan — full-leaf threefry would otherwise hold double-
-    buffered u32 bit tensors the size of the whole gradient (GiBs at 235B).
+    Key discipline: ``key`` is split once per leaf. A leaf with
+    ``ndim >= 2`` and ``shape[0] > 4`` takes row ``i``'s noise from
+    ``normal(fold_in(leaf_key, i), shape[1:])``; every other leaf takes one
+    ``normal(leaf_key, shape)``.
+
+    The rows of such a leaf are drawn in blocks of
+    ``max(1, NOISE_BLOCK_ELEMS // row_size)`` rows, each block one vmapped
+    draw over its row ids, added in place by a scan over the leaf; the
+    ``shape[0] % rows`` rows left take one more draw, and a leaf that fits
+    one block is drawn with no loop. The blocks give the same bits as row-by-row draws. One step
+    holds at most ``NOISE_BLOCK_ELEMS`` elements (or one row, where a row is
+    larger), so full-leaf threefry, whose u32 bit tensors would be the size
+    of the whole gradient (GiBs at 235B), is never materialised; and a leaf
+    of many short rows (an embedding, an output head) takes a few large
+    steps rather than one per row.
     """
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     keys = jax.random.split(key, len(leaves))
 
     def noisy(g, k):
-        if g.ndim >= 2 and g.shape[0] > 4:
-            def body(i, gl):
-                z = jax.random.normal(jax.random.fold_in(k, i),
-                                      gl.shape, gl.dtype)
-                return i + 1, gl + std * z
+        if g.ndim < 2 or g.shape[0] <= 4:
+            return g + std * jax.random.normal(k, g.shape, g.dtype)
+        n, row_shape, dtype = g.shape[0], g.shape[1:], g.dtype
+        rows = max(1, NOISE_BLOCK_ELEMS // math.prod(row_shape))
 
-            _, out = jax.lax.scan(body, 0, g)
-            return out
-        return g + std * jax.random.normal(k, g.shape, g.dtype)
+        def add_rows(blk, ids):
+            z = jax.vmap(lambda i: jax.random.normal(
+                jax.random.fold_in(k, i), row_shape, dtype))(ids)
+            return blk + std * z
+
+        if rows >= n:
+            return add_rows(g, jnp.arange(n))
+        # the loop carries the leaf in the sum's dtype (a bf16 leaf plus an
+        # f32 std is f32, as the one-shot draw above gives)
+        g = g.astype(jnp.result_type(g, std))
+
+        def body(gl, start):
+            blk = jax.lax.dynamic_slice_in_dim(gl, start, rows)
+            blk = add_rows(blk, start + jnp.arange(rows))
+            return jax.lax.dynamic_update_slice_in_dim(gl, blk, start, 0), None
+
+        n_full = n // rows * rows
+        g, _ = jax.lax.scan(body, g, jnp.arange(0, n_full, rows))
+        if n_full == n:
+            return g
+        return g.at[n_full:].set(add_rows(g[n_full:], jnp.arange(n_full, n)))
 
     return jax.tree_util.tree_unflatten(
         treedef, [noisy(g, k) for g, k in zip(leaves, keys, strict=True)])

@@ -81,6 +81,62 @@ def test_awgn_statistics(small_model, key):
     np.testing.assert_allclose(noisy["big"], noisy2["big"])
 
 
+# NOISE_BLOCK_ELEMS is patched to 256 elements: a 16-wide row gives blocks of
+# 16 rows, a [20, 16] row (320 elements) one row a step
+@pytest.mark.parametrize("shape,dtype", [
+    ((37, 16), jnp.float32),      # 2 blocks of 16 rows + 5 left over
+    ((48, 16), jnp.float32),      # 3 blocks, no remainder
+    ((6, 16), jnp.float32),       # one block: no loop
+    ((9, 20, 16), jnp.float32),   # a row above the budget: one row a step
+    ((4, 100), jnp.float32),      # shape[0] <= 4: one draw for the leaf
+    ((37, 16), jnp.bfloat16),
+], ids=["remainder", "even", "one_block", "row_over_budget", "short_lead",
+        "bf16"])
+def test_awgn_block_stream_matches_rows(monkeypatch, key, shape, dtype):
+    """The blocked draw gives the bits of one ``fold_in(leaf_key, i)`` draw
+    per row (or one draw per short leaf), whatever the block size."""
+    from repro.federated import rounds
+    monkeypatch.setattr(rounds, "NOISE_BLOCK_ELEMS", 256)
+    g = jax.random.normal(jax.random.PRNGKey(7), shape).astype(dtype)
+    grads = {"a": jnp.zeros((3,)), "b": g}
+    std = jnp.float32(0.5)
+
+    def per_row(g, key, std):
+        k_leaf = jax.random.split(key, 2)[1]
+        if shape[0] <= 4:
+            return g + std * jax.random.normal(k_leaf, shape, dtype)
+        z = jax.vmap(lambda i: jax.random.normal(
+            jax.random.fold_in(k_leaf, i), shape[1:], dtype))(
+                jnp.arange(shape[0]))
+        return g + std * z
+
+    got = jax.jit(rounds.add_awgn)(grads, key, std)["b"]
+    want = jax.jit(per_row)(g, key, std)
+    assert got.dtype == want.dtype
+    assert bool(jnp.array_equal(got, want))
+
+
+def test_awgn_embedding_loop_steps_follow_budget(monkeypatch, key):
+    """An embedding-shaped leaf [R, 896] loops over blocks of rows, never
+    over its R rows one at a time."""
+    from repro.federated import rounds
+    budget, r = 1 << 16, 1000
+    monkeypatch.setattr(rounds, "NOISE_BLOCK_ELEMS", budget)
+    jaxpr = jax.make_jaxpr(rounds.add_awgn)(
+        {"embed": jnp.zeros((r, 896))}, key, 1e-3)
+
+    def loop_lengths(jx):
+        for eqn in jx.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn.params["length"]
+            assert eqn.primitive.name != "while"
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from loop_lengths(sub)
+
+    lengths = list(loop_lengths(jaxpr.jaxpr))
+    assert lengths and max(lengths) <= r // (budget // 896)
+
+
 def test_per_client_losses_segment_mean(small_model, key):
     cfg, model, params = small_model
     batch = _fl_batch(cfg, key, n_clients=4, per_client=2)
